@@ -7,7 +7,9 @@ axis of same-topology variants produces) trains two ways:
 * **farm** — the pre-stacking path: per-cell jobs sharded over spawned
   worker processes (``cellfarm.resolve_cells(stack=False)``).  Every
   worker pays a fresh interpreter + JAX import, and every cell a fresh
-  jit compile.
+  jit compile.  On a TPU there is no pool — this process holds the chip —
+  so the same call resolves the cells one by one in-process
+  (``farm_mode`` in the BENCH line says which ran).
 * **stacked** — one ``jit(vmap(train_step))`` batch in-process
   (``cellstack.resolve_stacked``): one compile for the whole stack, the
   cell axis folded into the block-skip kernels' M dimension.
@@ -25,6 +27,8 @@ from __future__ import annotations
 import dataclasses
 import tempfile
 import time
+
+import jax
 
 from benchmarks.common import emit_json
 from repro.core import snn, workloads
@@ -75,6 +79,8 @@ def run(quick: bool = False):
         speedup = farm_dt / max(stack_dt, 1e-9)
         emit_json("cellstack/grid",
                   cells=n_cells,
+                  farm_mode=("pool" if jax.default_backend() == "cpu"
+                             else "in-process"),
                   farm_seconds=round(farm_dt, 3),
                   farm_cells_per_second=round(n_cells / max(farm_dt, 1e-9),
                                               3),

@@ -16,8 +16,10 @@ workloads (dvs-conv) are first-class cells: their Conv layers route through
 the patch-tiled block-skip kernel and their skip fractions are measured on
 the im2col patch matrices the kernel actually tiles.
 
-Wall-clock here is CPU-interpret (no TPU) — the hardware-independent figure
-of merit is the SKIP FRACTION.
+The kernels run the way ``repro.runtime`` decides from the platform: under
+``JAX_PLATFORMS=cpu`` they are interpreted, so wall-clock measures the
+Pallas interpreter and only the SKIP FRACTION carries over; on a TPU they
+compile to Mosaic and the timings are the chip's.
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit, emit_json, timed, timed_compile
+from repro import runtime
 from repro.core import encoding, snn, train_snn
 from repro.core.workloads import registry
 from repro.data import synthetic
@@ -72,7 +75,8 @@ def _micro(quick: bool) -> None:
         (out, us) = timed(lambda: ops.lif_step(
             u, s, c, beta=0.9, threshold=1.0)[0].block_until_ready(),
             repeats=1)
-        emit(f"kernels/lif_step/{shape[0]}x{shape[1]}", us, "interpret-mode")
+        emit(f"kernels/lif_step/{shape[0]}x{shape[1]}", us,
+             "interpret-mode" if runtime.kernel_interpret() else "compiled")
 
 
 def _layer_skip_fractions(cfg: snn.SNNConfig, params, spikes_in
@@ -175,7 +179,7 @@ def _bptt(quick: bool) -> None:
             # interpret-mode Pallas executes the B·OH·OW patch grid
             # serially — shrink the retina/batch so conv cells stay
             # benchmarkable on CPU (skip fractions are size-honest either
-            # way; wall-clock is CPU-interpret for every cell)
+            # way; on the CPU wall-clock measures the interpreter)
             wl = dataclasses.replace(
                 wl, input_shape=(8, 8, 2), batch_size=16, n_train=128,
                 num_steps_choices=(2,) if quick else (4, 8),

@@ -23,6 +23,9 @@ def main() -> None:
                          "service,fleet")
     args = ap.parse_args()
 
+    from repro import runtime
+    runtime.enable_compile_cache()
+
     from benchmarks import (bench_cellstack, bench_coexplore, bench_dse,
                             bench_explore, bench_extensions, bench_fig1,
                             bench_fig6, bench_fig7, bench_fleet,

@@ -13,7 +13,9 @@ blocks on lease/publish progress and would reclaim any cell whose
 heartbeat went stale (a SIGKILL'd worker, simulated below), training it
 in-process — so the study completes no matter how much of the fleet
 survives.  On a real cluster the root lives on a network mount and the
-workers on other hosts; nothing in the protocol changes.
+workers on other hosts; nothing in the protocol changes.  Here the workers
+stand in for those hosts and run on the CPU: a chip belongs to one process,
+and this one, the submitter, keeps the local accelerator.
 """
 import dataclasses
 import multiprocessing
@@ -22,6 +24,9 @@ import signal
 import tempfile
 import time
 
+import jax
+
+from repro import runtime
 from repro.core import dse, snn, workloads
 from repro.distributed import fleet
 
@@ -33,12 +38,18 @@ def tiny(name):
         n_train=128, n_test=64, train_steps=6, trace_samples=16)
 
 
+def cpu_worker(**kw):
+    """A fleet worker pinned to the CPU (set before its JAX starts)."""
+    jax.config.update("jax_platforms", "cpu")
+    fleet.run_worker(**kw)
+
+
 def main():
     wl = tiny("fleet-example-mlp")
     with tempfile.TemporaryDirectory() as root:
         ctx = multiprocessing.get_context("spawn")   # JAX is not fork-safe
         workers = [ctx.Process(
-            target=fleet.run_worker,
+            target=cpu_worker,
             kwargs=dict(root=root, worker_id=f"host-{i}", idle_timeout=20,
                         stats_path=os.path.join(root, f"stats-{i}.json")))
             for i in range(2)]
@@ -69,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    runtime.enable_compile_cache()
     main()

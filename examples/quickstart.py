@@ -11,10 +11,13 @@
 import jax
 import jax.numpy as jnp
 
+from repro import runtime
 from repro.core import dse, encoding, snn, sparsity, train_snn
 from repro.core.accelerator import arch as hw
 from repro.core.accelerator import resources
 from repro.data import synthetic
+
+runtime.enable_compile_cache()
 
 # 1. train -----------------------------------------------------------------
 data = synthetic.make_images(n_train=1024, n_test=256)

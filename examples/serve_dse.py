@@ -17,6 +17,7 @@ plus ``Progress`` cache/budget counters.
 import dataclasses
 import tempfile
 
+from repro import runtime
 from repro.core import snn, workloads
 from repro.serve import (DSEService, FrontierUpdate, Progress,
                          StudyCompleted, Submission)
@@ -82,4 +83,5 @@ def main():
 
 
 if __name__ == "__main__":
+    runtime.enable_compile_cache()
     main()

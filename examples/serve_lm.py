@@ -6,10 +6,12 @@ engine (continuous-batching bookkeeping, greedy sampling).
 import numpy as np
 import jax
 
+from repro import runtime
 from repro.launch.train import small_config
 from repro.models import registry
 from repro.serve import engine
 
+runtime.enable_compile_cache()
 base = registry.load_arch("tinyllama_1_1b")
 cfg = small_config(base, d_model=128, layers=2, vocab=512)
 params = registry.init_params(jax.random.key(0), cfg)

@@ -14,6 +14,7 @@ import math
 import jax
 import numpy as np
 
+from repro import runtime
 from repro.launch.train import run_training, small_config
 from repro.models import registry
 
@@ -52,4 +53,5 @@ def main():
 
 
 if __name__ == "__main__":
+    runtime.enable_compile_cache()
     main()

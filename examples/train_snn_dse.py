@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.core import dse, encoding, snn, train_snn, validate, workloads
 from repro.core.accelerator import arch as hw
 from repro.core.accelerator import cycle_model, resources
@@ -186,4 +187,5 @@ def main():
 
 
 if __name__ == "__main__":
+    runtime.enable_compile_cache()
     main()

@@ -9,13 +9,17 @@ it the pending ``(workload, assignment)`` jobs and a cache root, and it
 shards them over a spawned-process pool; afterwards every farmed cell
 resolves as a cache hit in the parent.
 
-Pool discipline: workers are spawned (not forked — JAX is not fork-safe
-once initialized), the pool size is explicitly capped at
-``min(jobs, cpu_count, MAX_POOL_WORKERS)`` so a 100-cell grid never spawns
-100 interpreters, the pool is REUSED across calls within one process
-(``Study`` steps in one ``explore()`` run share the already-imported
-workers; ``atexit`` tears it down), and job submission is chunked so each
-worker unpickles one slab instead of one job at a time.
+Pool discipline: the pool exists on the CPU backend only.  A chip belongs
+to one process, and the parent that resolves cells already holds it, so on
+a TPU every job resolves in-process (with ``stack=True``: every group
+stacks) and a failed stack raises.  On the CPU, workers are spawned (not
+forked — JAX is not fork-safe once initialized), the pool size is
+explicitly capped at ``min(jobs, cpu_count, MAX_POOL_WORKERS)`` so a
+100-cell grid never spawns 100 interpreters, the pool is REUSED across
+calls within one process (``Study`` steps in one ``explore()`` run share
+the already-imported workers; ``atexit`` tears it down), and job
+submission is chunked so each worker unpickles one slab instead of one
+job at a time.
 
 ``stack=True`` prefers *stacked* training over process farming: jobs are
 grouped by ``cellstack.stack_signature`` and every group that can amortize
@@ -43,6 +47,8 @@ import logging
 import multiprocessing
 import os
 from typing import Optional, Sequence, Union
+
+import jax
 
 from repro.core.workloads.cache import TraceCache, cell_key
 from repro.core.workloads.registry import Workload
@@ -106,9 +112,18 @@ def _resolve_job(args: tuple[CellJob, str]) -> CellOutcome:
                            error=f"{type(e).__name__}: {e}")
 
 
+def _holds_chip() -> bool:
+    """True off the CPU backend: this process holds the accelerator, and a
+    spawned child that imports JAX could not get it."""
+    return jax.default_backend() != "cpu"
+
+
 def _worker_count(n_jobs: int, workers: Optional[int]) -> int:
     """Effective pool size: explicit request, else one per job — both
-    capped at the CPU count and the module-level ``MAX_POOL_WORKERS``."""
+    capped at the CPU count and the module-level ``MAX_POOL_WORKERS``; 1
+    (in-process, no pool) when this process holds the chip."""
+    if _holds_chip():
+        return 1
     return min(workers if workers is not None else n_jobs,
                n_jobs, multiprocessing.cpu_count(), MAX_POOL_WORKERS)
 
@@ -194,8 +209,9 @@ def resolve_cells(jobs: Sequence[CellJob], root: str,
     poisoned pool, or a job that errors is retried up to ``retries``
     (default ``MAX_RETRIES``) extra rounds — the ``fault_tolerance``
     restart idiom — and then returned with ``CellOutcome.error`` set, so
-    one bad cell cannot kill a study or a service loop.  A failed stack
-    group degrades to farming before counting as a retry.
+    one bad cell cannot kill a study or a service loop.  On the CPU a
+    failed stack group degrades to farming before counting as a retry; on
+    a chip it raises.
 
     The parent's own ``TraceCache`` counters are untouched — count
     ``trained`` outcomes for miss accounting."""
@@ -225,8 +241,12 @@ def resolve_cells(jobs: Sequence[CellJob], root: str,
                 got = cellstack.resolve_stacked(
                     [jobs[i] for i in stacked_idx], root, **kw)
             except Exception as e:                       # noqa: BLE001
-                # a failed in-process stack is not fatal: the cells fall
-                # through to the farm/serial path below untouched
+                # on the CPU a failed in-process stack is not fatal: the
+                # cells fall through to the farm/serial path below
+                # untouched.  On the chip it raises — a cell must train by
+                # the route that was asked for.
+                if _holds_chip():
+                    raise
                 log.warning("stacked training failed (%s: %s); falling "
                             "back to farming %d cell(s)",
                             type(e).__name__, e, len(stacked_idx))

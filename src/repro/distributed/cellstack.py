@@ -161,7 +161,8 @@ def _train_slab(jobs: Sequence[CellJob],
     """Train one slab of same-signature jobs as a single vmapped stack.
     Returns per-job ``(params numpy, counts, accuracy)`` tuples in job
     order.  ``stats`` (optional) accumulates ``compile_seconds`` (first,
-    compiling stack-step call) and ``train_seconds``."""
+    compiling stack-step call) and ``train_seconds``, and records the
+    number of ``devices`` the stacked params live on."""
     job0 = jobs[0]
     wl0 = job0.workload
     T = int(job0.assignment["num_steps"])
@@ -211,6 +212,7 @@ def _train_slab(jobs: Sequence[CellJob],
         stats["train_seconds"] = (stats.get("train_seconds", 0.0)
                                   + time.perf_counter() - t0)
         stats["cells"] = stats.get("cells", 0) + C
+        stats["devices"] = len(jax.tree.leaves(params)[0].sharding.device_set)
 
     accuracy = _evaluate_stack(cfg, backend, params, datas, mesh)
     counts = _trace_stack(cfg, backend, params, datas, wl0.trace_samples,

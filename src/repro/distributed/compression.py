@@ -74,7 +74,7 @@ def make_compressed_grad_fn(loss_fn, mesh, batch_axes: tuple[str, ...] = ("data"
     residuals carried with a leading shard dim (they are LOCAL state — each
     shard keeps its own residual; see init_errors).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def per_shard(params, batch, errors):
         errors = jax.tree.map(lambda e: e[0], errors)      # drop shard dim
@@ -92,7 +92,7 @@ def make_compressed_grad_fn(loss_fn, mesh, batch_axes: tuple[str, ...] = ("data"
             per_shard, mesh=mesh,
             in_specs=(P(), P(ba), P(ba)),
             out_specs=(P(), P(), P(ba)),
-            check_rep=False)
+            check_vma=False)
         return fn(params, batch, errors)
 
     return grad_step

@@ -19,7 +19,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 PyTree = Any
@@ -71,7 +71,7 @@ def pipeline_apply(stage_fn: Callable, stage_params: PyTree,
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, micro_inputs)
 
 

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.runtime import kernel_interpret
+
 #: Default standalone-LIF tile, shared by this module and the ops.py wrapper
 #: (one constant, not two hardcodings).  ``block_b`` equals the training
 #: GEMM tile's ``block_m`` (``snn.KERNEL_BLOCKS`` derives from it): both are
@@ -45,8 +47,8 @@ def lif_step_pallas(u_prev: jax.Array, s_prev: jax.Array, current: jax.Array,
                     *, beta: float, threshold: float,
                     reset_mechanism: str = "subtract",
                     block_b: int = LIF_BLOCKS["block_b"],
-                    block_n: int = LIF_BLOCKS["block_n"],
-                    interpret: bool = False) -> tuple[jax.Array, jax.Array]:
+                    block_n: int = LIF_BLOCKS["block_n"]
+                    ) -> tuple[jax.Array, jax.Array]:
     """(B, N) fused LIF update.  Inputs must be pre-padded to block multiples
     (the ops.py wrapper handles padding/unpadding)."""
     B, N = u_prev.shape
@@ -62,5 +64,5 @@ def lif_step_pallas(u_prev: jax.Array, s_prev: jax.Array, current: jax.Array,
         out_specs=(spec, spec),
         out_shape=(jax.ShapeDtypeStruct((B, N), u_prev.dtype),
                    jax.ShapeDtypeStruct((B, N), u_prev.dtype)),
-        interpret=interpret,
+        interpret=kernel_interpret(),
     )(u_prev, s_prev, current)

@@ -1,6 +1,6 @@
 """Public jit'd wrappers around the Pallas kernels: padding, flag
-computation, dtype handling, and interpret-mode dispatch (this container has
-no TPU; ``interpret=True`` runs the kernel bodies on CPU for validation)."""
+computation and dtype handling.  Whether a kernel runs interpreted (CPU) or
+compiled (TPU) is decided from the platform by ``repro.runtime`` alone."""
 from __future__ import annotations
 
 import functools
@@ -27,12 +27,12 @@ def _pad_to(x: jax.Array, mults: tuple[int, ...]) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("beta", "threshold",
                                              "reset_mechanism", "block_b",
-                                             "block_n", "interpret"))
+                                             "block_n"))
 def lif_step(u_prev: jax.Array, s_prev: jax.Array, current: jax.Array, *,
              beta: float, threshold: float, reset_mechanism: str = "subtract",
              block_b: int = LIF_BLOCKS["block_b"],
-             block_n: int = LIF_BLOCKS["block_n"],
-             interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+             block_n: int = LIF_BLOCKS["block_n"]
+             ) -> tuple[jax.Array, jax.Array]:
     """Fused LIF update on arbitrary (B, N); pads to tile multiples.
 
     Default tile is ``lif_step.LIF_BLOCKS`` (shared with the kernel module;
@@ -42,8 +42,7 @@ def lif_step(u_prev: jax.Array, s_prev: jax.Array, current: jax.Array, *,
     args = [_pad_to(a, (block_b, block_n)) for a in (u_prev, s_prev, current)]
     u, s = lif_step_pallas(*args, beta=beta, threshold=threshold,
                            reset_mechanism=reset_mechanism,
-                           block_b=block_b, block_n=block_n,
-                           interpret=interpret)
+                           block_b=block_b, block_n=block_n)
     return u[:B, :N], s[:B, :N]
 
 
@@ -58,12 +57,11 @@ def block_flags(spikes: jax.Array, *, block_m: int = 128,
     return ref.block_flags_ref(s, block_m, block_k)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
 def spike_gemm(spikes: jax.Array, weights: jax.Array, *,
                flags: jax.Array = None,
-               block_m: int = 128, block_n: int = 128, block_k: int = 128,
-               interpret: bool = True) -> jax.Array:
+               block_m: int = 128, block_n: int = 128,
+               block_k: int = 128) -> jax.Array:
     """Sparsity-aware S @ W with block-level spike skipping.
 
     ``flags``: optional precomputed occupancy from ``block_flags`` (same
@@ -83,7 +81,7 @@ def spike_gemm(spikes: jax.Array, weights: jax.Array, *,
             f"block_k={block_k}; build them with ops.block_flags on the "
             f"same spike matrix and block sizes")
     out = spike_gemm_pallas(flags, s, w, block_m=block_m, block_n=block_n,
-                            block_k=block_k, interpret=interpret)
+                            block_k=block_k)
     return out[:M, :N]
 
 
@@ -102,12 +100,11 @@ def cotangent_block_flags(g: jax.Array, *, block_m: int = 128,
     return ref.block_flags_any_ref(gp, block_m, block_n)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
 def spike_gemm_bwd_dw(spikes: jax.Array, g: jax.Array, *,
                       flags: jax.Array = None,
                       block_m: int = 128, block_n: int = 128,
-                      block_k: int = 128, interpret: bool = True) -> jax.Array:
+                      block_k: int = 128) -> jax.Array:
     """dW[K,N] = Sᵀ·g with block-skip on the spike tiles.
 
     ``flags``: the FORWARD's occupancy array (``block_flags`` on the same
@@ -128,16 +125,15 @@ def spike_gemm_bwd_dw(spikes: jax.Array, g: jax.Array, *,
             f"of spikes {spikes.shape} at block_m={block_m}, "
             f"block_k={block_k}")
     dw = spike_gemm_dw_pallas(flags, s, gp, block_m=block_m, block_n=block_n,
-                              block_k=block_k, interpret=interpret)
+                              block_k=block_k)
     return dw[:K, :N]
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
 def spike_gemm_bwd_ds(g: jax.Array, weights: jax.Array, *,
                       gflags: jax.Array = None,
                       block_m: int = 128, block_n: int = 128,
-                      block_k: int = 128, interpret: bool = True) -> jax.Array:
+                      block_k: int = 128) -> jax.Array:
     """dS[M,K] = g·Wᵀ with block-skip on the cotangent tiles.
 
     Surrogate-gradient cotangents vanish wherever ``|u - θ|`` is large, so
@@ -157,7 +153,7 @@ def spike_gemm_bwd_ds(g: jax.Array, weights: jax.Array, *,
             f"gflags shape {gflags.shape} does not match the {want} tile "
             f"grid of g {g.shape} at block_m={block_m}, block_n={block_n}")
     ds = spike_gemm_ds_pallas(gflags, gp, w, block_m=block_m, block_n=block_n,
-                              block_k=block_k, interpret=interpret)
+                              block_k=block_k)
     return ds[:M, :K]
 
 
@@ -176,29 +172,29 @@ def spike_gemm_bwd_ds(g: jax.Array, weights: jax.Array, *,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _spike_gemm_train(blocks: tuple, spikes: jax.Array,
                       weights: jax.Array) -> jax.Array:
-    block_m, block_n, block_k, interpret = blocks
+    block_m, block_n, block_k = blocks
     return spike_gemm(spikes, weights, block_m=block_m, block_n=block_n,
-                      block_k=block_k, interpret=interpret)
+                      block_k=block_k)
 
 
 def _spike_gemm_train_fwd(blocks, spikes, weights):
-    block_m, block_n, block_k, interpret = blocks
+    block_m, block_n, block_k = blocks
     flags = block_flags(spikes, block_m=block_m, block_k=block_k)
     out = spike_gemm(spikes, weights, flags=flags, block_m=block_m,
-                     block_n=block_n, block_k=block_k, interpret=interpret)
+                     block_n=block_n, block_k=block_k)
     return out, (spikes, weights, flags)
 
 
 def _spike_gemm_train_bwd(blocks, res, g):
-    block_m, block_n, block_k, interpret = blocks
+    block_m, block_n, block_k = blocks
     spikes, weights, flags = res
     g32 = g.astype(jnp.float32)
     d_spikes = spike_gemm_bwd_ds(
-        g32, weights, block_m=block_m, block_n=block_n, block_k=block_k,
-        interpret=interpret).astype(spikes.dtype)
+        g32, weights, block_m=block_m, block_n=block_n,
+        block_k=block_k).astype(spikes.dtype)
     d_weights = spike_gemm_bwd_dw(
         spikes, g32, flags=flags, block_m=block_m, block_n=block_n,
-        block_k=block_k, interpret=interpret).astype(weights.dtype)
+        block_k=block_k).astype(weights.dtype)
     return d_spikes, d_weights
 
 
@@ -207,9 +203,9 @@ _spike_gemm_train.defvjp(_spike_gemm_train_fwd, _spike_gemm_train_bwd)
 
 def spike_gemm_train(spikes: jax.Array, weights: jax.Array, *,
                      block_m: int = 128, block_n: int = 128,
-                     block_k: int = 128, interpret: bool = True) -> jax.Array:
+                     block_k: int = 128) -> jax.Array:
     """Differentiable S @ W: block-skip Pallas forward and backward."""
-    return _spike_gemm_train((block_m, block_n, block_k, interpret),
+    return _spike_gemm_train((block_m, block_n, block_k),
                              spikes, weights)
 
 
@@ -223,12 +219,11 @@ def spike_gemm_train(spikes: jax.Array, weights: jax.Array, *,
 # dW/dS kernels of spike_gemm_bwd.py are reused verbatim.  DESIGN.md §13.
 
 @functools.partial(jax.jit, static_argnames=("stride", "padding", "block_m",
-                                             "block_n", "block_k",
-                                             "interpret"))
+                                             "block_n", "block_k"))
 def spike_conv(s_in: jax.Array, weights: jax.Array, *, stride: int = 1,
                padding: str = "SAME", flags: jax.Array = None,
-               block_m: int = 128, block_n: int = 128, block_k: int = 128,
-               interpret: bool = True) -> jax.Array:
+               block_m: int = 128, block_n: int = 128,
+               block_k: int = 128) -> jax.Array:
     """Sparsity-aware NHWC x HWIO convolution with patch-tile skipping.
 
     ``flags``: optional precomputed occupancy of the PATCH matrix
@@ -257,27 +252,26 @@ def spike_conv(s_in: jax.Array, weights: jax.Array, *, stride: int = 1,
             f"block_k={block_k}; build them with ops.block_flags on "
             f"ops.conv_patches of the same spike tensor")
     out = spike_conv_pallas(flags, p, w, block_m=block_m, block_n=block_n,
-                            block_k=block_k, interpret=interpret)
+                            block_k=block_k)
     return out[:B * oh * ow, :cout].reshape(B, oh, ow, cout)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _spike_conv_train(static: tuple, s_in: jax.Array,
                       weights: jax.Array) -> jax.Array:
-    stride, padding, block_m, block_n, block_k, interpret = static
+    stride, padding, block_m, block_n, block_k = static
     return spike_conv(s_in, weights, stride=stride, padding=padding,
-                      block_m=block_m, block_n=block_n, block_k=block_k,
-                      interpret=interpret)
+                      block_m=block_m, block_n=block_n, block_k=block_k)
 
 
 def _spike_conv_train_fwd(static, s_in, weights):
-    stride, padding, block_m, block_n, block_k, interpret = static
+    stride, padding, block_m, block_n, block_k = static
     kh, kw = weights.shape[:2]
     patches = conv_patches(s_in, kh, kw, stride, padding)
     flags = block_flags(patches, block_m=block_m, block_k=block_k)
     out = spike_conv(s_in, weights, stride=stride, padding=padding,
                      flags=flags, block_m=block_m, block_n=block_n,
-                     block_k=block_k, interpret=interpret)
+                     block_k=block_k)
     # the flags ride the residuals (PR-6 contract): the backward reuses the
     # forward's occupancy reduction instead of recomputing it.  The patch
     # matrix itself is NOT saved — it is cheap deterministic slicing of
@@ -287,7 +281,7 @@ def _spike_conv_train_fwd(static, s_in, weights):
 
 
 def _spike_conv_train_bwd(static, res, g):
-    stride, padding, block_m, block_n, block_k, interpret = static
+    stride, padding, block_m, block_n, block_k = static
     s_in, weights, flags = res
     kh, kw, cin, cout = weights.shape
     patch_fn = lambda x: conv_patches(x, kh, kw, stride, padding)
@@ -295,10 +289,10 @@ def _spike_conv_train_bwd(static, res, g):
     g2 = g.reshape(-1, cout).astype(jnp.float32)
     d_w = spike_gemm_bwd_dw(
         patches, g2, flags=flags, block_m=block_m, block_n=block_n,
-        block_k=block_k, interpret=interpret)
+        block_k=block_k)
     d_patches = spike_gemm_bwd_ds(
         g2, weights.reshape(kh * kw * cin, cout), block_m=block_m,
-        block_n=block_n, block_k=block_k, interpret=interpret)
+        block_n=block_n, block_k=block_k)
     # col2im: the exact linear transpose of conv_patches (pad + strided
     # slice + concat), derived by jax.vjp so overlap scatter-adds match the
     # dense conv's input cotangent bit for bit on grid operands.
@@ -311,12 +305,11 @@ _spike_conv_train.defvjp(_spike_conv_train_fwd, _spike_conv_train_bwd)
 
 def spike_conv_train(s_in: jax.Array, weights: jax.Array, *, stride: int = 1,
                      padding: str = "SAME", block_m: int = 128,
-                     block_n: int = 128, block_k: int = 128,
-                     interpret: bool = True) -> jax.Array:
+                     block_n: int = 128, block_k: int = 128) -> jax.Array:
     """Differentiable block-skip convolution: patch-tiled forward, block-skip
     dW/dS backward reusing the forward's flags from the VJP residuals."""
     return _spike_conv_train(
-        (int(stride), str(padding), block_m, block_n, block_k, interpret),
+        (int(stride), str(padding), block_m, block_n, block_k),
         s_in, weights)
 
 
@@ -342,7 +335,7 @@ def _spike_gemm_lif_train(static: tuple, spikes: jax.Array,
 
 
 def _spike_gemm_lif_fwd_impl(static, spikes, weights, bias, u_prev, s_prev):
-    (block_m, block_n, block_k, interpret,
+    (block_m, block_n, block_k,
      beta, threshold, slope, reset_mechanism) = static
     B, K = spikes.shape
     _, N = weights.shape
@@ -355,7 +348,7 @@ def _spike_gemm_lif_fwd_impl(static, spikes, weights, bias, u_prev, s_prev):
     u, sp = spike_gemm_lif_pallas(
         flags, s, w, b, u0, s0, beta=beta, threshold=threshold,
         reset_mechanism=reset_mechanism, block_m=block_m, block_n=block_n,
-        block_k=block_k, interpret=interpret)
+        block_k=block_k)
     return (u[:B, :N], sp[:B, :N]), flags
 
 
@@ -366,7 +359,7 @@ def _spike_gemm_lif_train_fwd(static, spikes, weights, bias, u_prev, s_prev):
 
 
 def _spike_gemm_lif_train_bwd(static, res, cots):
-    (block_m, block_n, block_k, interpret,
+    (block_m, block_n, block_k,
      beta, threshold, slope, reset_mechanism) = static
     spikes, weights, bias, u_prev, s_prev, u, flags = res
     gu, gs = cots
@@ -385,11 +378,11 @@ def _spike_gemm_lif_train_bwd(static, res, cots):
     g32 = g.astype(jnp.float32)
     d_bias = g32.sum(0).astype(bias.dtype)
     d_spikes = spike_gemm_bwd_ds(
-        g32, weights, block_m=block_m, block_n=block_n, block_k=block_k,
-        interpret=interpret).astype(spikes.dtype)
+        g32, weights, block_m=block_m, block_n=block_n,
+        block_k=block_k).astype(spikes.dtype)
     d_weights = spike_gemm_bwd_dw(
         spikes, g32, flags=flags, block_m=block_m, block_n=block_n,
-        block_k=block_k, interpret=interpret).astype(weights.dtype)
+        block_k=block_k).astype(weights.dtype)
     return d_spikes, d_weights, d_bias, d_u_prev.astype(u_prev.dtype), \
         d_s_prev.astype(s_prev.dtype)
 
@@ -404,7 +397,7 @@ def spike_gemm_lif_step(spikes: jax.Array, weights: jax.Array,
                         slope: float = 25.0,
                         reset_mechanism: str = "subtract",
                         block_m: int = 8, block_n: int = 128,
-                        block_k: int = 128, interpret: bool = True
+                        block_k: int = 128
                         ) -> tuple[jax.Array, jax.Array]:
     """Differentiable fused scan step: (u, s) = LIF(u, s, S @ W + b).
 
@@ -413,21 +406,19 @@ def spike_gemm_lif_step(spikes: jax.Array, weights: jax.Array,
     surrogate-gradient backward through the block-skip kernels.
     """
     return _spike_gemm_lif_train(
-        (block_m, block_n, block_k, interpret,
+        (block_m, block_n, block_k,
          float(beta), float(threshold), float(slope), reset_mechanism),
         spikes, weights, bias, u_prev, s_prev)
 
 
-@functools.partial(jax.jit, static_argnames=("capacity", "block_b",
-                                             "interpret"))
-def penc_compact(spikes: jax.Array, capacity: int, *, block_b: int = 8,
-                 interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+@functools.partial(jax.jit, static_argnames=("capacity", "block_b"))
+def penc_compact(spikes: jax.Array, capacity: int, *, block_b: int = 8
+                 ) -> tuple[jax.Array, jax.Array]:
     """Spike-address extraction (the ECU's PENC) on (B, N) spike rows."""
     from repro.kernels.penc_compact import penc_compact_pallas
     B, N = spikes.shape
     s = _pad_to(spikes, (block_b, 1))
-    idx, cnt = penc_compact_pallas(s, capacity=capacity, block_b=block_b,
-                                   interpret=interpret)
+    idx, cnt = penc_compact_pallas(s, capacity=capacity, block_b=block_b)
     return idx[:B], cnt[:B]
 
 

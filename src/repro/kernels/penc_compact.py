@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.runtime import kernel_interpret
+
 
 def _penc_kernel(s_ref, idx_ref, cnt_ref, *, capacity: int):
     s = s_ref[...]                                   # (block_b, N) {0,1}
@@ -39,8 +41,7 @@ def _penc_kernel(s_ref, idx_ref, cnt_ref, *, capacity: int):
 
 
 def penc_compact_pallas(spikes: jax.Array, *, capacity: int,
-                        block_b: int = 8,
-                        interpret: bool = False) -> tuple[jax.Array, jax.Array]:
+                        block_b: int = 8) -> tuple[jax.Array, jax.Array]:
     """spikes: (B, N) in {0,1} -> (indices (B, capacity) int32 with -1 pad,
     counts (B,) int32).  Spikes beyond ``capacity`` per row are dropped
     (the ECU's chunk bound); B must be a multiple of block_b (ops pads)."""
@@ -56,5 +57,5 @@ def penc_compact_pallas(spikes: jax.Array, *, capacity: int,
                    pl.BlockSpec((block_b,), lambda i: (i,))),
         out_shape=(jax.ShapeDtypeStruct((B, capacity), jnp.int32),
                    jax.ShapeDtypeStruct((B,), jnp.int32)),
-        interpret=interpret,
+        interpret=kernel_interpret(),
     )(spikes)

@@ -30,6 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.spike_gemm import pack_flags, tile_flag
+from repro.runtime import kernel_interpret
+
 
 def conv_out_size(size: int, kernel: int, stride: int,
                   padding: str) -> tuple[int, int, int]:
@@ -78,7 +81,7 @@ def _spike_conv_kernel(flags_ref, p_ref, w_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(flags_ref[i, k] != 0)
+    @pl.when(tile_flag(flags_ref, i, k, pl.num_programs(2)) != 0)
     def _accumulate():
         acc_ref[...] += jnp.dot(p_ref[...], w_ref[...],
                                 preferred_element_type=jnp.float32)
@@ -91,8 +94,7 @@ def _spike_conv_kernel(flags_ref, p_ref, w_ref, o_ref, acc_ref):
 def spike_conv_pallas(flags: jax.Array, patches: jax.Array,
                       weights: jax.Array, *, block_m: int = 128,
                       block_n: int = 128, block_k: int = 128,
-                      out_dtype=jnp.float32,
-                      interpret: bool = False) -> jax.Array:
+                      out_dtype=jnp.float32) -> jax.Array:
     """out[M,N] = patches[M,K] @ weights[K,N], skipping empty patch tiles.
 
     ``patches``: the im2col view (M = B·OH·OW receptive-field rows,
@@ -119,5 +121,5 @@ def spike_conv_pallas(flags: jax.Array, patches: jax.Array,
         _spike_conv_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        interpret=interpret,
-    )(flags, patches, weights)
+        interpret=kernel_interpret(),
+    )(pack_flags(flags), patches, weights)

@@ -9,7 +9,10 @@ skip granularity becomes a (block_m x block_k) tile of the spike matrix:
      (ops.py), the analogue of the ECU's compression pass;
   2. the flags ride in scalar-prefetch memory (SMEM) so the kernel knows,
      *before* the MXU touches a tile, whether it may skip the dot AND the
-     VMEM->MXU traffic for that tile;
+     VMEM->MXU traffic for that tile.  They travel bit-packed, 32 tiles to
+     an int32 word (``pack_flags``): a 2-D int32 array would be padded to
+     128 lanes per row in SMEM, and a conv patch matrix has thousands of
+     8-row tiles (DESIGN.md §13 states the bound);
   3. ``pl.when`` guards the accumulate — an all-zero spike tile costs one
      SMEM read instead of a 128x128x128 MAC block.
 
@@ -21,12 +24,43 @@ kernels that reuse these flags live in spike_gemm_bwd.py (DESIGN.md §12).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import kernel_interpret
+
+#: occupancy flags per packed SMEM word
+FLAG_BITS = 32
+#: SMEM one kernel call may spend on packed flags: a quarter of a v5e
+#: core's 1 MiB, the rest left to the compiler's own scalars (DESIGN §13)
+FLAG_SMEM_BYTES = 256 * 1024
+
+
+def pack_flags(flags: jax.Array) -> jax.Array:
+    """(rows, cols) 0/1 tile flags -> 1-D int32, bit ``t % 32`` of word
+    ``t // 32`` holding tile ``t = row * cols + col``.  The bits are
+    disjoint, so the sum is their OR and cannot carry."""
+    nbytes = 4 * -(-flags.size // FLAG_BITS)
+    if nbytes > FLAG_SMEM_BYTES:
+        raise ValueError(
+            f"{flags.size} tiles pack to {nbytes} bytes of flags, over the "
+            f"{FLAG_SMEM_BYTES}-byte SMEM budget of one kernel call; use "
+            f"larger tiles or split the call")
+    flat = flags.reshape(-1).astype(jnp.int32)
+    flat = jnp.pad(flat, (0, (-flat.size) % FLAG_BITS))
+    words = flat.reshape(-1, FLAG_BITS)
+    return jnp.left_shift(words, jnp.arange(FLAG_BITS, dtype=jnp.int32)
+                          ).sum(axis=1, dtype=jnp.int32)
+
+
+def tile_flag(flags_ref, row, col, cols: int):
+    """Occupancy of tile (row, col) from ``pack_flags`` words in SMEM."""
+    t = row * cols + col
+    word = flags_ref[t // FLAG_BITS]
+    return lax.shift_right_logical(word, t % FLAG_BITS) & 1
 
 
 def _spike_gemm_kernel(flags_ref, s_ref, w_ref, o_ref, acc_ref):
@@ -36,7 +70,7 @@ def _spike_gemm_kernel(flags_ref, s_ref, w_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(flags_ref[i, k] != 0)
+    @pl.when(tile_flag(flags_ref, i, k, pl.num_programs(2)) != 0)
     def _accumulate():
         acc_ref[...] += jnp.dot(s_ref[...], w_ref[...],
                                 preferred_element_type=jnp.float32)
@@ -48,8 +82,8 @@ def _spike_gemm_kernel(flags_ref, s_ref, w_ref, o_ref, acc_ref):
 
 def spike_gemm_pallas(flags: jax.Array, spikes: jax.Array, weights: jax.Array,
                       *, block_m: int = 128, block_n: int = 128,
-                      block_k: int = 128, out_dtype=jnp.float32,
-                      interpret: bool = False) -> jax.Array:
+                      block_k: int = 128, out_dtype=jnp.float32
+                      ) -> jax.Array:
     """out[M,N] = spikes[M,K] @ weights[K,N], skipping empty spike tiles.
 
     ``flags``: (M//block_m, K//block_k) int32 occupancy (see ref.block_flags_ref).
@@ -73,5 +107,5 @@ def spike_gemm_pallas(flags: jax.Array, spikes: jax.Array, weights: jax.Array,
         _spike_gemm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        interpret=interpret,
-    )(flags, spikes, weights)
+        interpret=kernel_interpret(),
+    )(pack_flags(flags), spikes, weights)

@@ -30,6 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.spike_gemm import pack_flags, tile_flag
+from repro.runtime import kernel_interpret
+
 
 def _dw_kernel(flags_ref, s_ref, g_ref, dw_ref, acc_ref):
     ki, m = pl.program_id(0), pl.program_id(2)
@@ -39,7 +42,7 @@ def _dw_kernel(flags_ref, s_ref, g_ref, dw_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # forward flags are (m, k)-indexed; the reduction index comes first here
-    @pl.when(flags_ref[m, ki] != 0)
+    @pl.when(tile_flag(flags_ref, m, ki, pl.num_programs(0)) != 0)
     def _accumulate():
         acc_ref[...] += jnp.dot(s_ref[...].T, g_ref[...],
                                 preferred_element_type=jnp.float32)
@@ -51,8 +54,8 @@ def _dw_kernel(flags_ref, s_ref, g_ref, dw_ref, acc_ref):
 
 def spike_gemm_dw_pallas(flags: jax.Array, spikes: jax.Array, g: jax.Array,
                          *, block_m: int = 128, block_n: int = 128,
-                         block_k: int = 128, out_dtype=jnp.float32,
-                         interpret: bool = False) -> jax.Array:
+                         block_k: int = 128, out_dtype=jnp.float32
+                         ) -> jax.Array:
     """dW[K,N] = spikes[M,K]ᵀ @ g[M,N], skipping empty spike tiles.
 
     ``flags``: the FORWARD's (M//block_m, K//block_k) occupancy array —
@@ -78,8 +81,8 @@ def spike_gemm_dw_pallas(flags: jax.Array, spikes: jax.Array, g: jax.Array,
         _dw_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, N), out_dtype),
-        interpret=interpret,
-    )(flags, spikes, g)
+        interpret=kernel_interpret(),
+    )(pack_flags(flags), spikes, g)
 
 
 def _ds_kernel(gflags_ref, g_ref, w_ref, ds_ref, acc_ref):
@@ -89,7 +92,7 @@ def _ds_kernel(gflags_ref, g_ref, w_ref, ds_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(gflags_ref[i, n] != 0)
+    @pl.when(tile_flag(gflags_ref, i, n, pl.num_programs(2)) != 0)
     def _accumulate():
         acc_ref[...] += jnp.dot(g_ref[...], w_ref[...].T,
                                 preferred_element_type=jnp.float32)
@@ -101,8 +104,8 @@ def _ds_kernel(gflags_ref, g_ref, w_ref, ds_ref, acc_ref):
 
 def spike_gemm_ds_pallas(gflags: jax.Array, g: jax.Array, weights: jax.Array,
                          *, block_m: int = 128, block_n: int = 128,
-                         block_k: int = 128, out_dtype=jnp.float32,
-                         interpret: bool = False) -> jax.Array:
+                         block_k: int = 128, out_dtype=jnp.float32
+                         ) -> jax.Array:
     """dS[M,K] = g[M,N] @ weights[K,N]ᵀ, skipping empty cotangent tiles.
 
     ``gflags``: (M//block_m, N//block_n) any-nonzero occupancy of ``g``
@@ -127,5 +130,5 @@ def spike_gemm_ds_pallas(gflags: jax.Array, g: jax.Array, weights: jax.Array,
         _ds_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, K), out_dtype),
-        interpret=interpret,
-    )(gflags, g, weights)
+        interpret=kernel_interpret(),
+    )(pack_flags(gflags), g, weights)

@@ -25,6 +25,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.spike_gemm import pack_flags, tile_flag
+from repro.runtime import kernel_interpret
+
 
 def _fused_kernel(flags_ref, s_ref, w_ref, b_ref, u_ref, sp_ref,
                   u_out_ref, s_out_ref, acc_ref, *,
@@ -35,7 +38,7 @@ def _fused_kernel(flags_ref, s_ref, w_ref, b_ref, u_ref, sp_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(flags_ref[i, k] != 0)
+    @pl.when(tile_flag(flags_ref, i, k, pl.num_programs(2)) != 0)
     def _accumulate():
         acc_ref[...] += jnp.dot(s_ref[...], w_ref[...],
                                 preferred_element_type=jnp.float32)
@@ -62,8 +65,7 @@ def spike_gemm_lif_pallas(flags: jax.Array, spikes: jax.Array,
                           beta: float, threshold: float,
                           reset_mechanism: str = "subtract",
                           block_m: int = 8, block_n: int = 128,
-                          block_k: int = 128,
-                          interpret: bool = False
+                          block_k: int = 128
                           ) -> tuple[jax.Array, jax.Array]:
     """(u, s) = LIF(u_prev, s_prev, spikes @ weights + bias) in one pass.
 
@@ -100,5 +102,5 @@ def spike_gemm_lif_pallas(flags: jax.Array, spikes: jax.Array,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((M, N), u_prev.dtype),
                    jax.ShapeDtypeStruct((M, N), u_prev.dtype)),
-        interpret=interpret,
-    )(flags, spikes, weights, bias, u_prev, s_prev)
+        interpret=kernel_interpret(),
+    )(pack_flags(flags), spikes, weights, bias, u_prev, s_prev)

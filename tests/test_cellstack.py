@@ -219,6 +219,37 @@ class TestResolveCellsStack:
         assert [o.key for o in outcomes] == [
             cell_key(j.workload, j.assignment, j.seed) for j in jobs]
 
+    def test_chip_backend_resolves_in_process_without_a_pool(self, tmp_path,
+                                                             monkeypatch):
+        """Off the CPU this process holds the chip, which a spawned child
+        could not get: workers>=2 builds no pool, cells resolve here."""
+        def boom(_):
+            raise AssertionError("pool constructed while holding the chip")
+        monkeypatch.setattr(cellfarm, "_get_pool", boom)
+        monkeypatch.setattr(cellfarm.multiprocessing, "cpu_count", lambda: 4)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert cellfarm._worker_count(10, 8) == 1
+        wl = _mlp()
+        jobs = [_job(wl, T=2, seed=0), _job(wl, T=3, seed=0)]
+        outcomes = cellfarm.resolve_cells(jobs, str(tmp_path), workers=2)
+        assert all(o.trained and o.error is None for o in outcomes)
+        assert [o.key for o in outcomes] == [
+            cell_key(j.workload, j.assignment, j.seed) for j in jobs]
+
+    def test_failed_stack_raises_on_the_chip_degrades_on_cpu(self, tmp_path,
+                                                             monkeypatch):
+        def refused(*args, **kwargs):
+            raise RuntimeError("stack refused")
+        monkeypatch.setattr(cellstack, "resolve_stacked", refused)
+        jobs = [_job(_mlp(), T=2, seed=0)]
+        out = cellfarm.resolve_cells(jobs, str(tmp_path / "cpu"), workers=0,
+                                     stack=True)
+        assert out[0].trained and out[0].error is None   # resolved serially
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="stack refused"):
+            cellfarm.resolve_cells(jobs, str(tmp_path / "tpu"), workers=0,
+                                   stack=True)
+
     def test_worker_count_caps(self, monkeypatch):
         monkeypatch.setattr(cellfarm.multiprocessing, "cpu_count",
                             lambda: 16)

@@ -135,7 +135,7 @@ class TestSparseBackwardExactness:
         matrix, and the backward consumes them as-is."""
         s = _spikes((16, 300), 0.05, seed=2)
         w = jax.random.normal(jax.random.key(3), (300, 40)) * 0.1
-        _, res = ops._spike_gemm_train_fwd((8, 128, 128, True), s, w)
+        _, res = ops._spike_gemm_train_fwd((8, 128, 128), s, w)
         saved_s, saved_w, saved_flags = res
         np.testing.assert_array_equal(
             np.asarray(saved_flags),
@@ -197,7 +197,7 @@ class TestConvVJP:
         recomputed)."""
         s, w = self._inputs(shape=(2, 12, 12, 2), cout=4, density=0.05,
                             seed=2)
-        static = (1, "SAME", 8, 128, 128, True)
+        static = (1, "SAME", 8, 128, 128)
         _, res = ops._spike_conv_train_fwd(static, s, w)
         saved_s, saved_w, saved_flags = res
         patches = ops.conv_patches(s, 3, 3, 1, "SAME")

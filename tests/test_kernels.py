@@ -349,6 +349,28 @@ class TestKernelPlumbing:
         assert sorted(np.asarray(perm).tolist()) == list(range(K))
 
 
+class TestPackedFlags:
+    """The tile flags reach SMEM bit-packed, 32 tiles per int32 word."""
+
+    @pytest.mark.parametrize("shape", [(1, 1), (3, 7), (8192, 1), (33, 2),
+                                       (4, 8)])
+    def test_every_tile_reads_back(self, shape):
+        from repro.kernels.spike_gemm import FLAG_BITS, pack_flags
+        flags = jax.random.bernoulli(KEY, 0.5, shape).astype(jnp.int32)
+        flags = flags.at[-1, -1].set(1)             # a set bit 31 included
+        words = np.asarray(pack_flags(flags)).astype(np.uint32)
+        assert words.shape == (-(-flags.size // FLAG_BITS),)
+        t = np.arange(flags.size)
+        bits = (words[t // FLAG_BITS] >> (t % FLAG_BITS)) & 1
+        np.testing.assert_array_equal(bits.reshape(shape), np.asarray(flags))
+
+    def test_over_the_smem_budget_raises(self):
+        from repro.kernels.spike_gemm import FLAG_SMEM_BYTES, pack_flags
+        tiles = 8 * FLAG_SMEM_BYTES + 1              # one word too many
+        with pytest.raises(ValueError, match="SMEM budget"):
+            pack_flags(jnp.zeros((tiles, 1), jnp.int32))
+
+
 class TestSpikeConvKernel:
     """Patch-tiled block-skip convolution (spike_conv.py) vs the dense
     ``lax.conv`` oracle — bit-for-bit on 1/256-grid weights, because with
