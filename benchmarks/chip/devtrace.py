@@ -10,8 +10,7 @@ Device planes are named ``/device:TPU:<n>``.  On each, the line
 text (``%spike_conv.22 = f32[65536,128]{...} custom-call(...)``), a loop
 holding the ops of its body; ``XLA Modules`` holds one event per program
 execution, named ``<program>(<id>)``.  Busy time is the union of the op
-intervals of one chip, averaged over the chips used.  A Pallas kernel is an
-op whose HLO text has ``custom_call_target="tpu_custom_call"``.
+intervals of one chip, averaged over the chips used.
 
     python3 benchmarks/chip/devtrace.py <trace.xplane.pb> <out.json> [N]
 
@@ -101,13 +100,8 @@ def program_name(event_name: str) -> str:
     return re.sub(r"\(\d+\)$", "", event_name)
 
 
-KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
 #: ops that hold other ops' intervals; left out of the ranking of ops
 CONTAINERS = ("while", "conditional", "call")
-
-
-def is_kernel(event: list) -> bool:
-    return KERNEL_MARK in event[0]
 
 
 def op_name(event_name: str) -> str:
@@ -146,7 +140,7 @@ def host_spans(trace: dict, names) -> list[tuple[str, float, float]]:
 def reduce(trace: dict, chips: int, window_ns: tuple[float, float] = None,
            span_names=(), top: int = 10) -> dict:
     """Busy and window seconds (mean over the first ``chips`` chips),
-    program executions and kernel events (of all those chips), the device
+    program executions (of all those chips), the device
     ops that took most time by op name (seconds per chip, loops left out),
     and the longest idle gaps of the first chip, each named by the
     innermost event, on the host line that holds a span in ``span_names``,
@@ -166,7 +160,7 @@ def reduce(trace: dict, chips: int, window_ns: tuple[float, float] = None,
             raise ValueError("the trace holds no device op")
         window_ns = (min(starts), max(ends))
     lo, hi = window_ns
-    busy, programs, kernels, op_time = [], {}, [], {}
+    busy, programs, op_time = [], {}, {}
     for plane, ops in zip(planes, ops_by_chip):
         busy.append(_union_ns([(max(s, lo), min(s + d, hi))
                                for _, s, d, _ in ops if s < hi and s + d > lo]))
@@ -178,8 +172,6 @@ def reduce(trace: dict, chips: int, window_ns: tuple[float, float] = None,
             name = op_name(event[0])
             if name not in CONTAINERS:
                 op_time[name] = op_time.get(name, 0.0) + event[2] * 1e-9
-            if is_kernel(event):
-                kernels.append(event)
     gaps = sorted(_gaps([(s, s + d) for _, s, d, _ in ops_by_chip[0]],
                         lo, hi), key=lambda g: g[0] - g[1])[:top]
     host = host_line(trace, set(span_names))
@@ -197,7 +189,6 @@ def reduce(trace: dict, chips: int, window_ns: tuple[float, float] = None,
         "window_s": (hi - lo) * 1e-9,
         "busy_s": sum(busy) / len(busy) * 1e-9,
         "programs": programs,
-        "kernels": kernels,
         "device_ops": sorted(([n, t / len(planes)]
                               for n, t in op_time.items()),
                              key=lambda x: -x[1])[:top],

@@ -1,15 +1,18 @@
 """One run of one cell: set-up, the measured window, the traced slice, the
-check of what the window published, and the result line.
+check of what the window produced, and the result line.
 
-The window drives the program's own cell-resolution path:
-``TraceCache.resolve`` for a ``solo`` route, one cell at a time, and
-``cellstack.resolve_stacked`` for a ``stacked`` route, whole slabs of
-``stack`` cells.  Each cell is trained from a fresh seed drawn from the
-run's seed, evaluated, traced and published to a ``TraceCache`` rooted in a
-temporary directory, so every cell is a miss.  Set-up resolves one unit of
-the same shapes (a cell or a slab) from seeds of its own, which compiles
-and loads every program the window runs.  The window then runs whole units
-until ``seconds`` have passed and ends when the last one is published.
+The window drives the program's own cell-resolution path,
+``TraceCache.resolve``, one cell at a time.  Each cell is trained from a
+fresh seed drawn from the run's seed, evaluated, traced and published to a
+``TraceCache`` rooted in a temporary directory, so every cell is a miss.
+Set-up resolves one cell of the same shapes from a seed of its own, which
+compiles and loads every program the window runs.  The window then runs
+whole cells until ``seconds`` have passed and ends when the last one is
+published.
+
+The per-step training losses of each cell, which ``train_snn.train``
+returns and ``TraceCache`` drops after training, are kept by cell seed
+(``Losses``) for the check.
 """
 from __future__ import annotations
 
@@ -93,34 +96,46 @@ def build_workload(config: dict, traffic: dict):
 
 @dataclasses.dataclass
 class Route:
-    """Resolves one unit of cells (one cell solo, or one slab stacked)
-    through the program's entry; returns how many of them failed."""
+    """Resolves one cell through the program's entry; returns 1 where it
+    failed, else 0."""
     workload: object
     assignment: dict
     traffic: dict
     cache: object
-    root: str
 
-    @property
-    def unit(self) -> int:
-        return (self.traffic["stack"] if self.traffic["route"] == "stacked"
-                else 1)
+    def resolve(self, seed: int) -> int:
+        if self.traffic["route"] != "solo":
+            raise ValueError(f"unknown route {self.traffic['route']!r}")
+        art = self.cache.resolve(self.workload, self.assignment, seed=seed,
+                                 quant_bits=())
+        return int(art.cache_hit)
 
-    def resolve(self, seeds: list[int]) -> int:
-        if self.traffic["route"] == "solo":
-            (seed,) = seeds
-            art = self.cache.resolve(self.workload, self.assignment,
-                                     seed=seed, quant_bits=())
-            return int(art.cache_hit)
-        if self.traffic["route"] == "stacked":
-            from repro.distributed import cellfarm, cellstack
-            jobs = [cellfarm.CellJob(self.workload, self.assignment, seed=s)
-                    for s in seeds]
-            outs = cellstack.resolve_stacked(jobs, self.root,
-                                             cache=self.cache,
-                                             max_stack=self.unit)
-            return sum(1 for o in outs if o.error or not o.trained)
-        raise ValueError(f"unknown route {self.traffic['route']!r}")
+
+class Losses:
+    """The per-step losses of every cell trained while ``kept`` is open, by
+    cell seed: ``TrainResult.train_loss`` of ``train_snn.train``, the
+    trainer that ``TraceCache.resolve`` calls, which returns them and whose
+    caller drops them.  The trainer runs unchanged; its result is read on
+    the way out."""
+
+    def __init__(self):
+        self.by_seed: dict[int, list[float]] = {}
+
+    @contextlib.contextmanager
+    def kept(self):
+        from repro.core import train_snn
+        train = train_snn.train
+
+        def keep(*args, **kwargs):
+            res = train(*args, **kwargs)
+            self.by_seed[int(kwargs.get("seed", 0))] = list(res.train_loss)
+            return res
+
+        train_snn.train = keep
+        try:
+            yield self
+        finally:
+            train_snn.train = train
 
 
 class Compiles:
@@ -158,6 +173,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     from repro import runtime
     if require_tpu:
         runtime.enable_compile_cache()
+        # keep every program set-up compiles: JAX keeps only those over 1 s
+        # by default, and the train step each cell re-jits compiles in less,
+        # so a window cell would compile it or load it by the cache's history
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     compiles = Compiles()
     jax.monitoring.register_event_duration_secs_listener(compiles)
     try:
@@ -180,45 +199,45 @@ def _run(spec, seed, seconds, trace, t_start, cat, device, peak, compiles,
     with contextlib.ExitStack() as stack:
         root = stack.enter_context(tempfile.TemporaryDirectory(
             prefix="chipbench-cells-"))
-        route = Route(wl, assignment, traffic, workloads.TraceCache(root),
-                      root)
-        n = route.unit
+        route = Route(wl, assignment, traffic, workloads.TraceCache(root))
+        losses = stack.enter_context(Losses().kept())
 
-        failed = route.resolve([next(seeds) for _ in range(n)])
-        if failed:
-            raise RuntimeError(f"set-up: {failed} of {n} cells failed")
+        if route.resolve(next(seeds)):
+            raise RuntimeError("set-up: the warm-up cell failed")
         setup_s = time.perf_counter() - t_start
         log(f"set-up {setup_s:.3f} s, compile {compiles.seconds['setup']:.3f}"
             f" s")
 
         compiles.phase = "window"
-        done, attempted, failed, published = 0, 0, 0, []
+        done, attempted, failed, published, cell_s = 0, 0, 0, [], []
         traced = None
         if trace:
             tdir = trace_dir or stack.enter_context(
                 tempfile.TemporaryDirectory(prefix="chipbench-trace-"))
         t0 = time.perf_counter()
         while True:
-            unit_seeds = [next(seeds) for _ in range(n)]
+            cell_seed = next(seeds)
             tracing = trace and traced is None and done > 0
             if tracing:
                 jax.profiler.start_trace(tdir)
                 u0 = time.perf_counter()
-            attempted += n
+            attempted += 1
+            c0 = time.perf_counter()
             try:
                 with jax.profiler.TraceAnnotation(RESOLVE_SPAN):
-                    bad = route.resolve(unit_seeds)
+                    bad = route.resolve(cell_seed)
             except Exception:                          # noqa: BLE001
                 traceback.print_exc(file=sys.stderr)
-                bad = n
+                bad = 1
             if tracing:
                 jax.profiler.stop_trace()
                 traced = time.perf_counter() - u0
-                traced_cells = n - bad
+                traced_cells = 1 - bad
+            cell_s.append(time.perf_counter() - c0)
             failed += bad
-            done += n - bad
+            done += 1 - bad
             if not bad:
-                published.extend(unit_seeds)
+                published.append(cell_seed)
             elapsed = time.perf_counter() - t0
             if elapsed >= seconds and (not trace or traced is not None):
                 break
@@ -226,6 +245,7 @@ def _run(spec, seed, seconds, trace, t_start, cat, device, peak, compiles,
         mem = memory_peak_bytes(cell["chips"])
         log(f"window {elapsed:.3f} s, {done} cells, compile "
             f"{compiles.seconds['window']:.3f} s, memory peak {mem}")
+        log("cell seconds " + " ".join(f"{c:.3f}" for c in cell_s))
 
         trace_path = None
         if trace:
@@ -233,7 +253,7 @@ def _run(spec, seed, seconds, trace, t_start, cat, device, peak, compiles,
                               recursive=True)
             trace_path = max(paths, key=os.path.getmtime) if paths else None
 
-        # the rate the window ran at outside the traced unit, whose start,
+        # the rate the window ran at outside the traced cell, whose start,
         # stop and dump of the profiler slow it
         untraced = (elapsed - traced, done - traced_cells) if trace else (
             elapsed, done)
@@ -250,7 +270,7 @@ def _run(spec, seed, seconds, trace, t_start, cat, device, peak, compiles,
         }
         if trace_path:
             tr = devtrace.load(trace_path)
-            # the traced slice is the traced unit's resolve span
+            # the traced slice is the traced cell's resolve span
             spans = devtrace.host_spans(tr, {RESOLVE_SPAN})
             record["trace"] = devtrace.reduce(
                 tr, cell["chips"],
@@ -265,7 +285,7 @@ def _run(spec, seed, seconds, trace, t_start, cat, device, peak, compiles,
                                    replace=False)) if published else []
         checks = verdict.check_cells(
             route.cache, wl, assignment, config,
-            [published[i] for i in sample])
+            [published[i] for i in sample], losses.by_seed)
 
     metrics = {}
     if trace:

@@ -12,10 +12,13 @@ training, evaluation and trace dump.  So one cell seed gives the same
 inputs, init and batches here as in the program, and only the arithmetic
 of training differs.
 
-``dtype="float32"`` computes everything in float32 with matmuls and convs at
-``highest`` precision: the reference.  ``dtype="bfloat16"`` holds
-parameters, optimizer state, membranes and spikes in bfloat16: the control,
-the next precision below the configuration's float32.
+``dtype="float32"`` holds everything in float32, with matmuls at the
+precision the configuration states (``matmul_precision``; JAX's
+``default``, which on a TPU is one bfloat16 pass with float32 sums and on a
+CPU full float32): the reference.  ``dtype="bfloat16"`` holds parameters,
+optimizer state, membranes and spikes in bfloat16: the control, the next
+precision below the configuration's float32.  ``precision="highest"``
+runs the matmuls at full float32 on any platform.
 """
 from __future__ import annotations
 
@@ -284,7 +287,7 @@ def _train(spec, params, key, x_tr, y_tr, idx):
     def one(carry, i):
         params, mu, nu, key, count = carry
         key, sub = jax.random.split(key)
-        _, g = _grad(spec, params, sub, x_tr[i], y_tr[i])
+        value, g = _grad(spec, params, sub, x_tr[i], y_tr[i])
         count = count + 1
         mu = jax.tree.map(lambda m, g: ADAM_B1 * m + (1 - ADAM_B1) * g, mu, g)
         nu = jax.tree.map(lambda v, g: ADAM_B2 * v
@@ -295,11 +298,11 @@ def _train(spec, params, key, x_tr, y_tr, idx):
             lambda p, m, v: p - lr * ((m / c1) / (jnp.sqrt(v / c2)
                                                   + ADAM_EPS)),
             params, mu, nu)
-        return (params, mu, nu, key, count), None
+        return (params, mu, nu, key, count), value
 
     carry = (params, zeros, zeros, key, jnp.zeros([], jnp.int32))
-    (params, *_), _ = jax.lax.scan(one, carry, idx)
-    return params, first
+    (params, *_), losses = jax.lax.scan(one, carry, idx)
+    return params, first, losses
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -334,15 +337,16 @@ def _counts(spec, params, xb):
 
 
 def train_cell(wl: dict, num_steps: int, population: float, seed: int,
-               dtype: str = "float32", keep_rows: int = None) -> dict:
-    """Train one cell from its seed and return what the program publishes
-    for it: params (numpy), test accuracy and the per-layer input spike
-    counts, plus the init and the first step's gradient of each leaf.
+               dtype: str = "float32", keep_rows: int = None,
+               precision: str = "default") -> dict:
+    """Train one cell from its seed and return what the program produces
+    for it: params (numpy), the loss of each training step, test accuracy
+    and the per-layer input spike counts, plus the init and the first
+    step's gradient of each leaf.
 
     ``keep_rows`` plants a fault for calibration: each step trains on the
     first ``keep_rows`` rows of its batch only, the mean taken over them."""
     dt = jnp.dtype(dtype)
-    precision = "highest" if dt == jnp.float32 else "default"
     layers = tuple(topology(wl, population))
     spec = (layers, int(num_steps), wl["num_classes"], dt.name, wl["lr"])
     x_tr, y_tr, x_te, y_te = make_data(wl, num_steps)
@@ -351,8 +355,9 @@ def train_cell(wl: dict, num_steps: int, population: float, seed: int,
     params0, key = init_params(seed, layers, wl["input_shape"])
     params0 = jax.tree.map(lambda a: a.astype(dt), params0)
     with jax.default_matmul_precision(precision):
-        params, first = _train(spec, params0, key, jnp.asarray(x_tr),
-                               jnp.asarray(y_tr), jnp.asarray(idx))
+        params, first, losses = _train(spec, params0, key,
+                                       jnp.asarray(x_tr), jnp.asarray(y_tr),
+                                       jnp.asarray(idx))
         correct, ekey = 0, jax.random.key(EVAL_SEED)
         for i in range(0, len(x_te), EVAL_BATCH):
             ekey, sub = jax.random.split(ekey)
@@ -363,5 +368,6 @@ def train_cell(wl: dict, num_steps: int, population: float, seed: int,
     as_np = lambda t: jax.tree.map(lambda a: np.asarray(a, np.float32), t)
     return {"params": as_np(params), "params0": as_np(params0),
             "first_grad": as_np(first),
+            "losses": np.asarray(losses, np.float64),
             "accuracy": correct / max(len(x_te), 1),
             "counts": [np.asarray(c, np.float32) for c in traced]}

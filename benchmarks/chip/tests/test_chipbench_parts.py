@@ -19,7 +19,7 @@ BENCH = catalog.Catalog().benchmark()
 def test_every_cell_loads_by_name(cell):
     spec = catalog.Catalog().cell(cell)
     assert spec["config"]["name"] == spec["cell"]["config"]
-    assert spec["traffic"]["route"] in ("solo", "stacked")
+    assert spec["traffic"]["route"] == "solo"
     assert {m["name"] for m in spec["end_to_end"]} >= {"setup_s",
                                                        "cells_per_min"}
     assert spec["per_layer"], "every cell reports a per-layer metric"
@@ -67,8 +67,6 @@ def test_dense_layer_work_by_hand():
     gemms = work.layer_gemms(net3, 1.0, 64)
     assert gemms == [(64, 784, 1024), (64, 1024, 1024), (64, 1024, 300)]
     assert work.gemm_flops(*gemms[0]) == 102_760_448
-    assert work.gemm_bytes(*gemms[0]) == 4 * (64 * 784 + 784 * 1024
-                                             + 64 * 1024)
     # 3 x T=51 x 2 x 64 x (784*1024 + 1024*1024 + 1024*300)
     assert work.train_step_flops(net3, 51, 1.0) == 42_273_865_728
 
@@ -87,8 +85,3 @@ def test_conv_layer_work_by_hand():
                      (64, 128, 16)]
     assert work.gemm_flops(*gemms[0]) == 37_748_736
 
-
-def test_least_time_takes_the_larger_bound():
-    peak = {"flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
-    assert work.least_seconds(1000, 10, peak) == 10.0
-    assert work.least_seconds(100, 100, peak) == 10.0

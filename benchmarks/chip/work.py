@@ -5,15 +5,11 @@ so each implementation is judged on the same work: 2*M*K*N operations for a
 Dense layer of batch M, and the im2col product M*(KH*KW*Cin)*Cout times two
 for a conv, with M = batch * OH * OW.  A training step runs the forward
 product and two backward ones (dW and dS) of the same size, so it counts
-three times the forward.  Bytes are float32 operands and results, each read
-or written once.
+three times the forward.
 """
 from __future__ import annotations
 
-import itertools
 import math
-
-F32 = 4
 
 
 def topology(wl: dict, population: float) -> list[tuple]:
@@ -56,65 +52,9 @@ def gemm_flops(m: int, k: int, n: int) -> int:
     return 2 * m * k * n
 
 
-def gemm_bytes(m: int, k: int, n: int) -> int:
-    """The two operands and the result of one product."""
-    return F32 * (m * k + k * n + m * n)
-
-
 def train_step_flops(wl: dict, num_steps: int, population: float) -> int:
     """Dense operations of one training step of one cell: forward and the
     two backward products of every layer, at every time step."""
     fwd = sum(gemm_flops(*g)
               for g in layer_gemms(wl, population, wl["batch_size"]))
     return 3 * fwd * num_steps
-
-
-def least_seconds(flops: float, nbytes: float, peak: dict) -> float:
-    """The least time the chip could take: the larger of operations over
-    peak FLOP/s and bytes over peak memory bandwidth."""
-    return max(flops / peak["flops_per_s"], nbytes / peak["hbm_bytes_per_s"])
-
-
-#: a kernel pads a dimension of w to less than max(2 w, w + PAD_SLACK)
-PAD_SLACK = 256
-
-
-def cell_products(config: dict) -> list[tuple]:
-    """Every layer product of the cell, unpadded (M, K, N), at each batch it
-    runs: the training batch, each evaluation batch and the traced
-    samples."""
-    wl = config["workload"]
-    batches = {wl["batch_size"], wl["trace_samples"]}
-    batches |= {min(256, wl["n_test"] - i) for i in range(0, wl["n_test"], 256)}
-    return sorted({g for b in batches
-                   for g in layer_gemms(wl, config["population"], b)})
-
-
-def _pads(want: tuple, face: tuple):
-    """Padding of ``face`` over ``want``, or None where it cannot be that
-    face padded to a tile multiple."""
-    if all(w <= d < max(2 * w, w + PAD_SLACK) for w, d in zip(want, face)):
-        return sum(face) - sum(want)
-    return None
-
-
-def match_product(shapes: list[tuple], products: list[tuple]):
-    """The unpadded (M, K, N) of a kernel call whose operand and result
-    shapes are ``shapes``: the product the most of whose faces (M, K),
-    (K, N), (M, N) they are once padded (two at least, each by a shape of
-    its own), and of several the one padded least.  None when none
-    matches."""
-    faces = sorted({tuple(s) for s in shapes if len(s) == 2})
-    best = None
-    for prod in products:
-        m, k, n = prod
-        wants = ((m, k), (k, n), (m, n))
-        r = min(3, len(faces))
-        for fs in itertools.permutations(faces, r):
-            for ws in itertools.permutations(wants, r):
-                pads = [_pads(w, f) for w, f in zip(ws, fs)]
-                pads = [x for x in pads if x is not None]
-                score = (-len(pads), sum(pads))
-                if len(pads) >= 2 and (best is None or score < best[0]):
-                    best = (score, prod)
-    return best[1] if best else None
