@@ -83,11 +83,12 @@ def make_train_step(cfg: snn.SNNConfig, tx,
                     matmul_backend: Optional[str] = None):
     """One SGD step of the training loop as a pure ``(params, opt_state,
     key, x, y) -> (params, opt_state, loss)`` function — unjitted, so
-    callers can wrap it in ``jax.jit`` directly (the solo loop below) or
-    ``jax.vmap`` it over a leading cell axis first
-    (``distributed.cellstack`` trains whole same-signature cell stacks
-    through this exact function, which is what keeps stacked and solo
-    training bit-identical)."""
+    callers can wrap it in ``jax.jit`` (the solo loop below, through the
+    per-signature cache ``_jitted_train_step``) or ``jax.vmap`` it over a
+    leading cell axis first (``distributed.cellstack`` trains whole
+    same-signature cell stacks through this exact function, which is what
+    keeps stacked and solo training bit-identical).  The cache holds one
+    executable per signature, for the last ``SIGNATURES_KEPT`` signatures."""
     backend = snn.resolve_matmul_backend(matmul_backend)
 
     def train_step(params, opt_state, key, x, y):
@@ -99,6 +100,22 @@ def make_train_step(cfg: snn.SNNConfig, tx,
         return params, opt_state, loss
 
     return train_step
+
+
+# Signatures whose jitted train step and trace dump stay compiled, well
+# above the few dozen cells of a Fig-8 sweep.  Past it the least recently
+# used signature's programs are dropped, and JAX frees its executables.
+SIGNATURES_KEPT = 64
+
+
+@functools.lru_cache(maxsize=SIGNATURES_KEPT)
+def _jitted_train_step(make, cfg: snn.SNNConfig, lr: float, backend: str):
+    """``jax.jit(make(cfg, optim.adam(lr), backend))``, built once per
+    ``(make, cfg, lr, backend)`` and reused by every later cell of that
+    signature, so a cell traces and lowers no train step of its own; jit's
+    own cache still keys the shapes (batch, T).  ``make`` is part of the key
+    so a replaced ``make_train_step`` gets a program of its own."""
+    return jax.jit(make(cfg, optim.adam(lr), backend))
 
 
 def init_cell(cfg: snn.SNNConfig, tx, seed: int):
@@ -121,7 +138,7 @@ def train(cfg: snn.SNNConfig, data: synthetic.Dataset, *,
     backend = snn.resolve_matmul_backend(matmul_backend)
     tx = optim.adam(lr)
     params, opt_state, key = init_cell(cfg, tx, seed)
-    train_step = jax.jit(make_train_step(cfg, tx, backend))
+    train_step = _jitted_train_step(make_train_step, cfg, lr, backend)
 
     losses = []
     it = synthetic.batches(data.x_train, data.y_train, batch_size,
@@ -140,6 +157,16 @@ def train(cfg: snn.SNNConfig, data: synthetic.Dataset, *,
     return TrainResult(params=params, train_loss=losses, test_accuracy=acc, cfg=cfg)
 
 
+@functools.lru_cache(maxsize=SIGNATURES_KEPT)
+def _jitted_dump(cfg: snn.SNNConfig, backend: str):
+    """The jitted ``snn.spike_counts_per_layer`` of one ``(cfg, backend)``,
+    kept like ``_jitted_train_step``."""
+    def spike_counts(params: PyTree, spikes_in: jax.Array) -> list[jax.Array]:
+        return snn.spike_counts_per_layer(cfg, params, spikes_in,
+                                          matmul_backend=backend)
+    return jax.jit(spike_counts)
+
+
 def dump_traces(cfg: snn.SNNConfig, params: PyTree, x: np.ndarray,
                 seed: int = 7, max_samples: int = 64,
                 matmul_backend: Optional[str] = None) -> dict:
@@ -153,8 +180,8 @@ def dump_traces(cfg: snn.SNNConfig, params: PyTree, x: np.ndarray,
     key = jax.random.key(seed)
     xb = jnp.asarray(x[:max_samples])
     spikes_in = _encode_input(key, xb, cfg.num_steps)
-    counts = snn.spike_counts_per_layer(cfg, params, spikes_in,
-                                        matmul_backend=matmul_backend)
+    dump = _jitted_dump(cfg, snn.resolve_matmul_backend(matmul_backend))
+    counts = dump(params, spikes_in)
     return {
         "layer_input_spike_counts": [np.asarray(c) for c in counts],
         "layer_sizes": cfg.layer_sizes(),
