@@ -13,6 +13,7 @@ every spiking layer's output train is returned so that
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 from typing import Any, Optional, Sequence, Union
@@ -20,7 +21,7 @@ from typing import Any, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
-from repro.core.lif import LIFParams, lif_step
+from repro.core.lif import LIFParams, lif_step, spike_fn
 from repro.kernels import ops as kernel_ops
 
 PyTree = Any
@@ -298,6 +299,162 @@ def step(cfg: SNNConfig, params: PyTree, states: list, s_in: jax.Array,
     return new_states, spikes
 
 
+# ---------------------------------------------------------------------------
+# The time loop and its BPTT memory
+# ---------------------------------------------------------------------------
+
+#: Bytes of carries (each spiking layer's float32 membrane and spikes) that
+#: one BPTT scan may keep for all its time steps.  Past it the backward
+#: pass is rematerialised (``remat_chunk``).  At batch 64, net-3 (T 51)
+#: keeps about 61 MB and ``dvs-conv`` (T 16) about 0.1 GB, under it; net-5
+#: (128x128x2 events, T 124) would keep 41.7 GB.
+REMAT_BUDGET_BYTES = 2 ** 30
+
+
+def remat_chunk(cfg: SNNConfig, batch: int, num_steps: int) -> int:
+    """Time steps in each rematerialised chunk of the BPTT scan over
+    ``num_steps`` steps at ``batch``: ``num_steps`` itself (one scan that
+    keeps every step's residuals) where all steps' carries fit
+    ``REMAT_BUDGET_BYTES``, else about sqrt(T), so the backward pass holds
+    the carry entering each chunk and one chunk's carries at a time."""
+    carry_bytes = 2 * 4 * sum(cfg.layer_sizes())
+    if num_steps * batch * carry_bytes <= REMAT_BUDGET_BYTES:
+        return num_steps
+    return max(1, round(math.sqrt(num_steps)))
+
+
+def _entering(cfg: SNNConfig, s_in: jax.Array,
+              spikes: list[jax.Array]) -> list[jax.Array]:
+    """The spikes entering each spiking layer at one time step: the input,
+    then every spiking layer's output but the last, OR-pooled by the pools
+    that follow it (the hardware's ECU sees the pooled train)."""
+    trains, outputs = [s_in], iter(spikes)
+    for spec in cfg.layers:
+        if isinstance(spec, MaxPool):
+            if len(trains) > 1:
+                trains[-1] = _or_pool(trains[-1], spec.window)
+        else:
+            trains.append(next(outputs))
+    return trains[:-1]
+
+
+def _run(cfg: SNNConfig, params: PyTree, spike_input: jax.Array, emit,
+         matmul_backend: Optional[str], layer_perms: Optional[Sequence]):
+    """``emit(s_in, spikes)`` of every time step of the net over a (T, B,
+    ...) input train, stacked over time.  Each step's input is cast to
+    float32 on the device inside the scan, so an input train held in bytes
+    is never widened whole.  Where ``remat_chunk`` picks chunks shorter
+    than T, the backward pass is rematerialised (``_remat_scan``); the
+    result is the same either way."""
+    backend = resolve_matmul_backend(matmul_backend)
+    num_steps, batch = spike_input.shape[:2]
+
+    def body(params, perms, states, s_in):
+        s_in = s_in.astype(jnp.float32)
+        new_states, spikes = step(cfg, params, states, s_in,
+                                  matmul_backend=backend, layer_perms=perms)
+        return new_states, emit(s_in, spikes)
+
+    states0 = init_states(cfg, batch)
+    chunk = remat_chunk(cfg, batch, num_steps)
+    if chunk >= num_steps:
+        return jax.lax.scan(functools.partial(body, params, layer_perms),
+                            states0, spike_input)[1]
+
+    # a kept carry is each layer's membrane, flat: its spikes are the
+    # membrane over threshold, as the LIF update made them
+    def pack(states):
+        return [None if st is None else st[0].reshape(-1) for st in states]
+
+    def unpack(flat):
+        states = []
+        for spec, u, zero in zip(cfg.layers, flat, states0):
+            if u is not None:
+                u = u.reshape(zero[0].shape)
+                u = (u, spike_fn(u - spec.lif.threshold, spec.lif.slope))
+            states.append(u)
+        return states
+
+    return _remat_scan(body, chunk, pack, unpack, params, layer_perms,
+                       states0, spike_input)
+
+
+def _chunks(a: jax.Array, chunk: int) -> jax.Array:
+    """A time-major array padded with zeros at its end to whole chunks, as
+    (chunks, chunk, ...)."""
+    pad = jnp.zeros((-a.shape[0] % chunk,) + a.shape[1:], a.dtype)
+    a = jnp.concatenate([a, pad])
+    return a.reshape((-1, chunk) + a.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _remat_scan(body, chunk, pack, unpack, params, consts, carry, xs):
+    """The stacked outputs of ``body(params, consts, carry, x) -> (carry,
+    y)`` over ``xs``'s leading axis, with a rematerialised backward pass:
+    the forward keeps the carry entering each chunk of ``chunk`` steps, as
+    ``pack(carry)``, and the backward recomputes one chunk's carries at a
+    time from it (``unpack`` rebuilds the carry bit for bit).
+
+    It is written out, not left to ``jax.checkpoint`` of each chunk's scan:
+    autodiff of a scan of scans sums the parameters' gradient chunk by
+    chunk and then over chunks, which rounds otherwise than one scan.  Here
+    it is one running sum from step T back to step 1, in one scan's order,
+    so the gradient is one scan's, bit for bit.  Where ``chunk`` does not
+    divide T, the time axis is padded at its end with zero-input steps
+    whose outputs are dropped: their cotangents are zero, so they add exact
+    zeros to that sum.  ``consts`` (the layer permutations) and ``xs`` (the
+    input train, data) get no cotangent."""
+    return jax.lax.scan(functools.partial(body, params, consts), carry,
+                        xs)[1]
+
+
+def _remat_scan_fwd(body, chunk, pack, unpack, params, consts, carry, xs):
+    def part(c, x):
+        end, ys = jax.lax.scan(functools.partial(body, params, consts), c, x)
+        return end, (pack(c), ys)
+
+    _, (starts, ys) = jax.lax.scan(part, carry, _chunks(xs, chunk))
+    num_steps = xs.shape[0]
+    ys = jax.tree.map(lambda y: y.reshape((-1,) + y.shape[2:])[:num_steps],
+                      ys)
+    return ys, (params, consts, xs, starts)
+
+
+def _remat_scan_bwd(body, chunk, pack, unpack, res, ct_ys):
+    params, consts, xs, starts = res
+
+    def back_chunk(cts, part):
+        start, x, ct_y = part
+        with jax.named_scope("bptt_recompute"):
+            _, entering = jax.lax.scan(
+                lambda c, x: (body(params, consts, c, x)[0], pack(c)),
+                unpack(start), x)
+
+        def back_step(cts, inputs):
+            acc, ct_carry = cts
+            c, x, ct_y = inputs
+            with jax.named_scope("bptt_recompute"):
+                _, vjp = jax.vjp(lambda p, c: body(p, consts, c, x), params,
+                                 unpack(c))
+            g_params, ct_carry = vjp((ct_carry, ct_y))
+            return (jax.tree.map(jnp.add, acc, g_params), ct_carry), None
+
+        return jax.lax.scan(back_step, cts, (entering, x, ct_y),
+                            reverse=True)[0], None
+
+    carry = unpack(jax.tree.map(lambda s: s[0], starts))
+    cts = (jax.tree.map(jnp.zeros_like, params),
+           jax.tree.map(jnp.zeros_like, carry))
+    (g_params, ct_carry), _ = jax.lax.scan(
+        back_chunk, cts,
+        (starts, _chunks(xs, chunk),
+         jax.tree.map(lambda a: _chunks(a, chunk), ct_ys)), reverse=True)
+    return g_params, None, ct_carry, None
+
+
+_remat_scan.defvjp(_remat_scan_fwd, _remat_scan_bwd)
+
+
 def apply(cfg: SNNConfig, params: PyTree, spike_input: jax.Array,
           return_all_layers: bool = False,
           matmul_backend: Optional[str] = None,
@@ -309,19 +466,9 @@ def apply(cfg: SNNConfig, params: PyTree, spike_input: jax.Array,
     ``matmul_backend``/``layer_perms`` select the accumulate-phase execution
     path (see ``_layer_current``); results are backend-invariant.
     """
-    backend = resolve_matmul_backend(matmul_backend)
-    batch = spike_input.shape[1]
-    states0 = init_states(cfg, batch)
-
-    def scan_fn(states, s_in):
-        new_states, spikes = step(cfg, params, states, s_in,
-                                  matmul_backend=backend,
-                                  layer_perms=layer_perms)
-        out = spikes if return_all_layers else spikes[-1]
-        return new_states, out
-
-    _, collected = jax.lax.scan(scan_fn, states0, spike_input)
-    return collected
+    def emit(s_in, spikes):
+        return spikes if return_all_layers else spikes[-1]
+    return _run(cfg, params, spike_input, emit, matmul_backend, layer_perms)
 
 
 def layer_input_trains(cfg: SNNConfig, params: PyTree,
@@ -331,31 +478,15 @@ def layer_input_trains(cfg: SNNConfig, params: PyTree,
     """The (T, B, ...) spike train **entering** each spiking layer.
 
     Entry ``l`` is spiking layer ``l``'s input traffic (entry 0 is the
-    encoded input train); pooling between layers is applied first, because
-    the hardware's ECU sees the pooled train.  This is the statistic behind
-    both the cycle model (``spike_counts_per_layer``) and the profile-guided
-    tile permutation (``train_snn.profiled_permutations``).
+    encoded input train, in float32); pooling between layers is applied
+    first, because the hardware's ECU sees the pooled train.  This is the
+    statistic behind both the cycle model (``spike_counts_per_layer``) and
+    the profile-guided tile permutation
+    (``train_snn.profiled_permutations``).
     """
-    all_spikes = apply(cfg, params, spike_input, return_all_layers=True,
-                       matmul_backend=matmul_backend)
-    # Build the input train of each spiking layer: input spikes, then each
-    # spiking layer's output (pooled if a MaxPool follows it).
-    trains = [spike_input]
-    spiking_idx = 0
-    layer_list = list(cfg.layers)
-    for i, spec in enumerate(layer_list):
-        if isinstance(spec, (Dense, Conv)):
-            train = all_spikes[spiking_idx]
-            # apply any pooling that immediately follows
-            j = i + 1
-            while j < len(layer_list) and isinstance(layer_list[j], MaxPool):
-                w = layer_list[j].window
-                train = jax.vmap(lambda s: _or_pool(s, w))(train)
-                j += 1
-            trains.append(train)
-            spiking_idx += 1
-    # drop the final output train: it feeds no further layer
-    return trains[:-1]
+    return _run(cfg, params, spike_input,
+                lambda s_in, spikes: _entering(cfg, s_in, spikes),
+                matmul_backend, None)
 
 
 def spike_counts_per_layer(cfg: SNNConfig, params: PyTree,
@@ -366,8 +497,11 @@ def spike_counts_per_layer(cfg: SNNConfig, params: PyTree,
     statistic that drives the accelerator cycle model.
 
     Entry ``l`` counts spikes entering spiking layer ``l`` (so entry 0 counts
-    the encoded input train); see ``layer_input_trains``.
+    the encoded input train); see ``layer_input_trains``.  The counts are
+    summed inside the time loop, step by step, so no layer's whole train is
+    held.
     """
-    trains = layer_input_trains(cfg, params, spike_input,
-                                matmul_backend=matmul_backend)
-    return [t.reshape(t.shape[0], t.shape[1], -1).sum(-1) for t in trains]
+    def emit(s_in, spikes):
+        return [t.reshape(t.shape[0], -1).sum(-1)
+                for t in _entering(cfg, s_in, spikes)]
+    return _run(cfg, params, spike_input, emit, matmul_backend, None)

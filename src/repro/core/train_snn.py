@@ -41,7 +41,8 @@ class TrainResult:
 
 
 def _encode_input(key: jax.Array, x: jax.Array, num_steps: int) -> jax.Array:
-    if x.ndim == 5:        # pre-encoded event data (B, T, H, W, C)
+    if x.ndim == 5:        # pre-encoded event data (B, T, H, W, C), in
+        # the type it is held in: ``snn.apply`` casts one step at a time
         return x.transpose(1, 0, 2, 3, 4)
     return encoding.rate_encode(key, x, num_steps)
 

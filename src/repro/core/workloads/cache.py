@@ -322,7 +322,8 @@ class TraceCache:
 
     def _train(self, workload: Workload, cfg: snn.SNNConfig, T: int,
                seed: int):
-        data = workload.make_data(T)
+        with jax.profiler.TraceAnnotation("cell.data"):
+            data = workload.make_data(T)
         res = train_snn.train(cfg, data, steps=workload.train_steps,
                               batch_size=workload.batch_size,
                               lr=workload.lr, seed=seed,

@@ -24,6 +24,7 @@ way) and register them under new names.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence
 
 from repro.core import snn
@@ -100,14 +101,15 @@ class Workload:
     # ---- data -------------------------------------------------------------
     def make_data(self, num_steps: int) -> synthetic.Dataset:
         """Deterministic dataset for one cell.  Event data is generated at
-        the cell's T (the stream length IS the spike train); image data is
-        T-independent (rate encoding happens in training)."""
+        the cell's T (the stream length IS the spike train) and the last
+        event dataset is kept, read-only, so that the cells one process
+        trains share one generation; image data is T-independent (rate
+        encoding happens in training)."""
         if self.dataset == "dvs":
             h, w, _ = self.input_shape
-            return synthetic.make_events(
-                name=f"synth-{self.name}", seed=self.data_seed,
-                num_classes=self.num_classes, n_train=self.n_train,
-                n_test=self.n_test, t=int(num_steps), h=h, w=w)
+            return _events(f"synth-{self.name}", self.data_seed,
+                           self.num_classes, self.n_train, self.n_test,
+                           int(num_steps), h, w)
         return synthetic.make_images(
             name=f"synth-{self.name}", seed=self.data_seed,
             num_classes=self.num_classes, n_train=self.n_train,
@@ -136,6 +138,19 @@ class Workload:
             "lr": self.lr, "trace_samples": self.trace_samples,
             "version": self.version,
         }
+
+
+@functools.lru_cache(maxsize=1)
+def _events(name: str, seed: int, num_classes: int, n_train: int,
+            n_test: int, t: int, h: int, w: int) -> synthetic.Dataset:
+    """``synthetic.make_events``, its arrays made read-only since every
+    caller of the kept dataset shares them."""
+    data = synthetic.make_events(name=name, seed=seed,
+                                 num_classes=num_classes, n_train=n_train,
+                                 n_test=n_test, t=t, h=h, w=w)
+    for a in (data.x_train, data.y_train, data.x_test, data.y_test):
+        a.flags.writeable = False
+    return data
 
 
 def _scale(spec: snn.LayerSpec, population: float) -> snn.LayerSpec:
